@@ -51,6 +51,7 @@ from repro.api import (
     KEY_MAX, NOT_FOUND, TOMBSTONE, OP_DELETE, OP_INSERT, OP_SEARCH,
     LifecyclePolicy, OpBatch, Uruv, UruvConfig,
 )
+from repro.launch.cache import enable_compile_cache
 
 WIDTHS = [64, 256, 1024, 4096]
 
@@ -804,6 +805,7 @@ def main() -> None:
                     help="fig8|fig9|complexity|kernels|mixed|range|"
                          "lifecycle|index|serve|wal|roofline")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     sections = {
         "fig8": lambda: fig8(args.quick),

@@ -282,7 +282,10 @@ class ShardedExecutor:
     flagged pool (maintain burst / doubling / tracker-gated compact) and
     retry, bounded by ``MAX_SLOWPATH_ROUNDS``; with ``auto_grow=False``
     a fully-rejected announce raises ``CapacityError`` (size shards for
-    the working set — there is no sharded halving slow path).
+    the working set).  An announce that routes more than ``leaf_cap`` new
+    keys to one leaf of a shard (``OFLOW_LEAFBATCH``: a wide insert plan
+    into a nearly empty store) is applied in halves at its original
+    timestamps, the slow path of ``core.batch``.
     """
 
     def __init__(self, config: _sharded.ShardedConfig, mesh, *,
@@ -389,6 +392,20 @@ class ShardedExecutor:
                 return store, np.asarray(res)
             except RuntimeError as e:    # full rejection: relieve + retry
                 reason = getattr(e, "oflow_reason", 0)
+                if reason & _store.OFLOW_LEAFBATCH and len(codes) > 1:
+                    # > L new keys for one leaf of some shard: help in
+                    # rounds as core.batch does — each half runs at its
+                    # own slice of the announce timestamps (the clock
+                    # advances per half), so results are unchanged
+                    self.stats["slow_path_rounds"] += 1
+                    mid = len(codes) // 2
+                    store, res_a = self._apply_crud(
+                        store, codes[:mid], keys[:mid], values[:mid],
+                        light_path)
+                    store, res_b = self._apply_crud(
+                        store, codes[mid:], keys[mid:], values[mid:],
+                        light_path)
+                    return store, np.concatenate([res_a, res_b])
                 grow_bits = reason & (_store.OFLOW_LEAVES
                                       | _store.OFLOW_VERSIONS)
                 index_bit = reason & _store.OFLOW_INDEX

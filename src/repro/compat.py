@@ -1,45 +1,31 @@
-"""Version-compatibility shims for the installed jax.
+"""Shims over the installed jax (0.9) for the APIs the store relies on.
 
-Centralises the two API moves that differ across the jax versions this
-repo runs on (container pins vs TPU-image nightlies):
+  * :func:`shard_map` — ``jax.shard_map`` with the varying-manual-axes
+    check (``check_vma``) off by default.  The store's passes were written
+    against shard_map's untyped replication semantics: branches of a
+    ``lax.cond`` inside a shard (``store._bulk_apply_impl``'s search
+    resolve) may differ in which mesh axes they vary over, and every
+    collective in ``repro.core.sharded`` is explicit.
+  * :func:`make_mesh` — ``jax.make_mesh`` with Auto axis types.
 
-  * ``jax.sharding.AxisType`` / ``jax.make_mesh(..., axis_types=...)`` —
-    absent before jax 0.5; :func:`make_mesh` falls back to the plain call.
-  * ``jax.shard_map`` — lives under ``jax.experimental.shard_map`` on
-    older versions.
-
-Import from here instead of feature-testing jax at each call site.
+Import from here instead of calling jax at each call site.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
 
-    HAS_AXIS_TYPES = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-    HAS_AXIS_TYPES = False
-
-if hasattr(jax, "shard_map"):  # pragma: no cover - depends on installed jax
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, **kwargs):
-        # the experimental replication checker predates rules for several
-        # primitives the store uses (while_loop); disable it by default.
-        kwargs.setdefault("check_rep", False)
-        return _shard_map(f, **kwargs)
+def shard_map(f, **kwargs):
+    """``jax.shard_map`` with ``check_vma=False`` unless the caller sets it."""
+    kwargs.setdefault("check_vma", False)
+    return jax.shard_map(f, **kwargs)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types when the API supports them."""
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(AxisType.Auto,) * len(axis_names),
+    )

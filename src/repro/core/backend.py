@@ -3,30 +3,32 @@
 The store's three inner loops — ``locate`` (multi-level fat-node descent
 + in-leaf rank; DESIGN.md Sec 11), ``resolve`` (versioned chain read),
 and ``range_scan`` (fused leaf-window gather + versioned resolve for
-batched range queries) — have three interchangeable implementations with
-one contract:
+batched range queries) — have three implementations with one contract:
 
   * ``xla``              — pure-jnp formulation (gather/compare-reduce
-    descent via ``repro.core.index``, ``while_loop`` chain walk).  Lowers
-    on every backend; the portable default off-TPU.
-  * ``pallas``           — the compiled Pallas TPU kernels
+    descent via ``repro.core.index``, ``while_loop`` chain walk).  Compiles
+    for every platform, the TPU v5e included; the default everywhere.
+  * ``pallas``           — the Pallas TPU kernels
     (``repro.kernels.uruv_search`` + ``repro.kernels.versioned_read`` +
-    ``repro.kernels.uruv_range``).  Deployment configuration on real TPUs.
+    ``repro.kernels.uruv_range``), an explicit opt-in.  ``leaf_slots``
+    compiles for v5e; ``index_descend``, ``versioned_read`` and
+    ``range_scan`` gather rows from whole pools held in VMEM, which
+    Mosaic does not lower, so a store pass on this backend fails with the
+    compiler's own error.  Nothing catches it or swaps in another
+    backend.
   * ``pallas_interpret`` — the same kernels under the Pallas interpreter;
-    kernel-coverage testing on CPU containers.
+    kernel coverage on CPU.
 
 Resolution order: :func:`set_backend` override > ``URUV_BACKEND`` env var >
-auto-detect (TPU -> ``pallas``, anything else -> ``xla``).  The chosen
-backend is threaded as a *static* argument through the store's jitted entry
-points, so switching backends retraces rather than silently reusing a stale
-compilation.
+``xla``.  The chosen backend is threaded as a *static* argument through the
+store's jitted entry points, so switching backends retraces rather than
+silently reusing a stale compilation.
 """
 
 from __future__ import annotations
 
 import os
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -56,13 +58,13 @@ def set_backend(name: str | None) -> None:
 
 
 def get_backend() -> str:
-    """Resolve the active backend: override > env > auto-detect."""
+    """Resolve the active backend: override > env > ``xla``."""
     if _override is not None:
         return _override
     env = os.environ.get(ENV_VAR)
     if env:
         return _validate(env)
-    return PALLAS if jax.default_backend() == "tpu" else XLA
+    return XLA
 
 
 # ---------------------------------------------------------------------------

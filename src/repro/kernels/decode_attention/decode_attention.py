@@ -90,7 +90,7 @@ def decode_attention(
     lengths: jax.Array,  # [B] int32 — valid cache length per sequence
     *,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
     return_stats: bool = False,
 ):
     B, H, D = q.shape

@@ -16,7 +16,7 @@ from repro.analysis.marks import device_pass
 @functools.partial(
     jax.jit, static_argnames=("use_pallas", "interpret", "block_k")
 )
-def decode(q, k, v, lengths, *, use_pallas=False, interpret=True, block_k=512):
+def decode(q, k, v, lengths, *, use_pallas=False, interpret=False, block_k=512):
     if use_pallas:
         return decode_attention(
             q, k, v, lengths, block_k=block_k, interpret=interpret

@@ -111,7 +111,7 @@ def flash_attention(
     window: int = 0,    # 0 = full attention; >0 = sliding window size
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     B, H, Sq, D = q.shape
     _, KVH, Skv, _ = k.shape

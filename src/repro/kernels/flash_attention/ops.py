@@ -20,7 +20,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 )
 def attention(
     q, k, v, *, causal=True, window=0,
-    use_pallas=False, interpret=True, block_q=128, block_k=128,
+    use_pallas=False, interpret=False, block_q=128, block_k=128,
 ):
     if use_pallas:
         return flash_attention(

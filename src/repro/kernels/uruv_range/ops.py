@@ -28,7 +28,7 @@ def range_scan(
     lids, pvalid, k1, k2, snap_ts,
     leaf_keys, leaf_vhead, leaf_count, ver_ts, ver_next, ver_value,
     *, max_chain: int = 16, block_q: int = 128, use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """(cand_keys, cand_vals) [Q, S*L]; non-hits are (KEY_MAX, NOT_FOUND)."""
     if use_pallas:

@@ -15,10 +15,12 @@ budget.  The scan window loop (``scan_leaves``) and the chain walk
 (``max_chain``) are static unrolls; compaction of hits into the per-query
 result block stays in XLA (sort-based, see ``store.bulk_range``).
 
-Hardware note: vectorized dynamic gather from VMEM lowers via Mosaic's
-dynamic-gather on current TPU toolchains; this container validates the
-kernel in interpret mode, and ref.py provides the pure-jnp oracle that the
-``xla`` backend serves as the portable fallback.
+Hardware note (DESIGN.md Sec 7): Mosaic does not lower this kernel for
+TPU v5e — the leaf-row gather ``lkeys[lid]`` is refused ("Shape mismatch
+in input, indices and output"), and at the paper configuration the
+whole-pool VMEM blocks alone exceed 20 MiB.  It runs in interpret mode
+only; ref.py provides the pure-jnp oracle that the default ``xla``
+backend serves.
 """
 
 from __future__ import annotations
@@ -94,7 +96,7 @@ def range_scan(
     *,
     max_chain: int = 16,
     block_q: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Candidate phase of Q range queries: (cand_keys, cand_vals) [Q, S*L].
 

@@ -2,10 +2,10 @@
 
 ``locate()`` is the full traversal contract (multi-level fat-node descent
 -> leaf gather -> in-leaf slot), switchable between the Pallas path and
-the XLA oracle.  The store routes through `repro.core.backend.locate`,
-which auto-detects TPU (compiled Pallas) vs anything else (XLA) with a
-`URUV_BACKEND` override; this module remains the kernel-level entry used
-by the interpret-mode sweeps (see DESIGN.md Sec 7 / Sec 11).
+the XLA oracle.  The store routes through `repro.core.backend.locate`
+(``xla`` unless `URUV_BACKEND` or `set_backend` picks a Pallas backend);
+this module remains the kernel-level entry used by the interpret-mode
+sweeps (see DESIGN.md Sec 7 / Sec 11).
 """
 
 from __future__ import annotations
@@ -30,7 +30,7 @@ def locate(
     queries: jax.Array,
     *,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (bottom_node, bottom_slot, leaf_id, slot, exists)."""
     if use_pallas:

@@ -63,7 +63,7 @@ def search_positions(
     *,
     block_q: int = 256,
     block_dir: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """pos[i] = searchsorted(dir_keys, queries[i], side='right') - 1."""
     P = queries.shape[0]
@@ -129,7 +129,7 @@ def index_descend(
     queries: jax.Array,
     *,
     block_q: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Root->leaf descent: returns (bottom_node, bottom_slot, leaf_id)
     of the last separator <= q — the kernel twin of
@@ -160,11 +160,13 @@ def _slot_kernel(rows_ref, q_ref, slot_ref, exists_ref):
     rows = rows_ref[...]                  # [BQ, L]
     q = q_ref[...]                        # [BQ]
     slot = jnp.sum((rows < q[:, None]).astype(jnp.int32), axis=1)
-    L = rows.shape[1]
-    hit_idx = jnp.minimum(slot, L - 1)
-    hit = jnp.take_along_axis(rows, hit_idx[:, None], axis=1)[:, 0]
+    # membership as a compare-reduce: the key at ``slot`` equals q (an
+    # in-kernel row gather does not lower on Mosaic); slot == L matches
+    # no column, so a full leaf with every key < q reports absent
+    col = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+    hit = (col == slot[:, None]) & (rows == q[:, None])
     slot_ref[...] = slot
-    exists_ref[...] = ((slot < L) & (hit == q)).astype(jnp.int32)
+    exists_ref[...] = jnp.max(hit.astype(jnp.int32), axis=1)
 
 
 @device_pass(static=("block_q", "interpret"))
@@ -173,10 +175,13 @@ def leaf_slots(
     rows: jax.Array,
     queries: jax.Array,
     *,
-    block_q: int = 256,
-    interpret: bool = True,
+    block_q: int = 1024,
+    interpret: bool = False,
 ):
-    """In-leaf rank + membership for pre-gathered leaf rows [P, L]."""
+    """In-leaf rank + membership for pre-gathered leaf rows [P, L].
+
+    ``block_q`` defaults to 1024: a 1-D int32 block of a longer array
+    must match XLA's T(1024) tiling on TPU."""
     P, L = rows.shape
     bq = min(block_q, P)
     pad = (-P) % bq

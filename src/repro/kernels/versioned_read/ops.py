@@ -18,7 +18,7 @@ from repro.analysis.marks import device_pass
 )
 def resolve(
     vhead, snap_ts, ver_ts, ver_next, ver_value,
-    *, max_chain: int = 16, use_pallas: bool = True, interpret: bool = True,
+    *, max_chain: int = 16, use_pallas: bool = True, interpret: bool = False,
 ):
     if use_pallas:
         return versioned_read(

@@ -9,10 +9,11 @@ pinned in VMEM while query tiles stream through, so every chain step is a
 VMEM-latency gather instead of an HBM round-trip.  That is the TPU analogue
 of the paper's pointer walk staying in L1/L2.
 
-Hardware note (DESIGN.md Sec 2): vectorized dynamic gather from VMEM lowers
-via Mosaic's dynamic-gather on current TPU toolchains; this container
-validates the kernel in interpret mode, and ops.py exposes the XLA-gather
-oracle as the portable fallback path.
+Hardware note (DESIGN.md Sec 7): Mosaic does not lower this kernel for
+TPU v5e — the 1-D table gather ``ts_tab[safe]`` is refused with "Only 2D
+gather is supported".  It runs in interpret mode only; the store's
+default ``xla`` backend is the chain walk that compiles for the chip, and
+ops.py exposes the XLA-gather oracle.
 """
 
 from __future__ import annotations
@@ -58,7 +59,7 @@ def versioned_read(
     *,
     max_chain: int = 16,
     block_q: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     P = vhead.shape[0]
     MV = ver_ts.shape[0]

@@ -2,8 +2,8 @@
 
 Functions (never module-level constants) so importing this module never
 touches jax device state.  Mesh construction goes through
-``repro.compat.make_mesh`` so the same code runs on jax versions with and
-without ``jax.sharding.AxisType`` (DESIGN.md Sec 2 notes the compat rule).
+``repro.compat.make_mesh`` (Auto axis types; DESIGN.md Sec 2 notes the
+compat rule).
 """
 
 from __future__ import annotations

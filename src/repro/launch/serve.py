@@ -15,6 +15,7 @@ import numpy as np
 import jax
 
 from repro.config import get_arch
+from repro.launch.cache import enable_compile_cache
 from repro.models.registry import get_model
 from repro.serve.engine import Engine, Request
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

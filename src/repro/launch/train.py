@@ -14,6 +14,7 @@ import argparse
 import dataclasses
 
 from repro.config import get_arch
+from repro.launch.cache import enable_compile_cache
 from repro.train.loop import TrainLoopConfig, train
 
 
@@ -30,6 +31,7 @@ def main():
                     help="override width (e.g. ~100M quickstart)")
     ap.add_argument("--layers", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -436,6 +436,50 @@ print("SHARDED_API_OK")
 """
 
 
+SHARDED_WIDE_INSERT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path.insert(0, "src")
+import numpy as np
+from repro.compat import make_mesh
+from repro.api import OpBatch, ShardedConfig, Uruv, UruvConfig
+from repro.core.ref import RefStore, OP_INSERT
+
+mesh = make_mesh((4,), ("data",))
+base = UruvConfig(leaf_cap=8, max_leaves=64, max_versions=1024)
+db = Uruv.sharded(ShardedConfig(base=base, key_lo=0, key_hi=4000), mesh)
+local = Uruv(base)
+ref = RefStore()
+rng = np.random.default_rng(5)
+for _ in range(3):
+    keys = rng.choice(4000, 128, replace=False).astype(np.int32)
+    vals = rng.integers(1, 1000, 128).astype(np.int32)
+    r_sh = db.apply(OpBatch.inserts(keys, vals))
+    r_lo = local.apply(OpBatch.inserts(keys, vals))
+    want = ref.apply_batch([(OP_INSERT, int(k), int(v))
+                            for k, v in zip(keys, vals)])
+    assert r_sh.values.tolist() == r_lo.values.tolist() == want
+    assert db.ts == local.ts == ref.ts
+assert db.stats["slow_path_rounds"] > 0
+assert db.live_items() == local.live_items() == ref.live_items()
+print("SHARDED_WIDE_OK")
+"""
+
+
+def test_sharded_wide_insert_into_empty_store_halves():
+    """A 128-wide insert plan routes > leaf_cap new keys to one leaf of
+    each empty shard; the sharded executor applies it in halves at the
+    original timestamps (the local slow path) instead of raising."""
+    proc = subprocess.run(
+        [sys.executable, "-c", SHARDED_WIDE_INSERT_SCRIPT],
+        cwd=Path(__file__).resolve().parents[1],
+        capture_output=True, text=True, timeout=900,
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "SHARDED_WIDE_OK" in proc.stdout
+
+
 def test_sharded_executor_matches_local_subprocess():
     proc = subprocess.run(
         [sys.executable, "-c", SHARDED_API_SCRIPT],

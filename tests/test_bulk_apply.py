@@ -204,7 +204,7 @@ def test_backend_resolution_env_and_override(monkeypatch):
     finally:
         BK.set_backend(None)
     monkeypatch.delenv(BK.ENV_VAR)
-    assert BK.get_backend() in BK.BACKENDS
+    assert BK.get_backend() == BK.XLA      # default on every platform
     with pytest.raises(ValueError):
         BK.set_backend("tpu9000")
 

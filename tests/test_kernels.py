@@ -28,7 +28,7 @@ def test_search_positions_sweep(n_dir, n_q, bq, bd):
     d[0] = -(2**31)
     q = RNG.integers(-10, 10**6 + 10, n_q).astype(np.int32)
     got = search_positions(jnp.asarray(d), jnp.asarray(q),
-                           block_q=bq, block_dir=bd)
+                           block_q=bq, block_dir=bd, interpret=True)
     want = search_positions_ref(jnp.asarray(d), jnp.asarray(q))
     np.testing.assert_array_equal(got, want)
 
@@ -57,7 +57,7 @@ def test_index_descend_sweep(fanout, n_sep, n_q, bq):
         seps[:8], seps[:8] + 1, np.array([KEY_MAX - 1], np.int32),
     ])
     got = index_descend(idx.node_keys, idx.node_child, jnp.asarray(q),
-                        block_q=bq)
+                        block_q=bq, interpret=True)
     want = index_descend_ref(idx.node_keys, idx.node_child, jnp.asarray(q))
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
@@ -72,7 +72,8 @@ def test_index_descend_sweep(fanout, n_sep, n_q, bq):
 def test_leaf_slots_sweep(P, L, bq):
     rows = np.sort(RNG.integers(0, 500, (P, L)), axis=1).astype(np.int32)
     q = RNG.integers(0, 520, P).astype(np.int32)
-    s1, e1 = leaf_slots(jnp.asarray(rows), jnp.asarray(q), block_q=bq)
+    s1, e1 = leaf_slots(jnp.asarray(rows), jnp.asarray(q), block_q=bq,
+                        interpret=True)
     s2, e2 = leaf_slots_ref(jnp.asarray(rows), jnp.asarray(q))
     np.testing.assert_array_equal(s1, s2)
     np.testing.assert_array_equal(e1, e2)
@@ -106,7 +107,7 @@ def test_versioned_read_sweep(MV, P, chain):
     snap = RNG.integers(0, 50, P).astype(np.int32)
     a = versioned_read(jnp.asarray(vh), jnp.asarray(snap), jnp.asarray(ts),
                        jnp.asarray(nxt), jnp.asarray(val),
-                       max_chain=chain, block_q=64)
+                       max_chain=chain, block_q=64, interpret=True)
     b = versioned_read_ref(jnp.asarray(vh), jnp.asarray(snap),
                            jnp.asarray(ts), jnp.asarray(nxt),
                            jnp.asarray(val), max_chain=chain)
@@ -183,7 +184,7 @@ def test_flash_attention_sweep(B, H, KVH, S, D, causal, win, dtype):
         q, k, v = map(jnp.asarray, (q, k, v))
         tol = 2e-5
     a = flash_attention(q, k, v, causal=causal, window=win,
-                        block_q=32, block_k=32)
+                        block_q=32, block_k=32, interpret=True)
     b = attention_ref(q, k, v, causal=causal, window=win)
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32),
@@ -198,7 +199,7 @@ def test_decode_attention_sweep(B, H, KVH, S, D, bk):
     k = jnp.asarray(RNG.standard_normal((B, KVH, S, D)), jnp.float32)
     v = jnp.asarray(RNG.standard_normal((B, KVH, S, D)), jnp.float32)
     lens = jnp.asarray(RNG.integers(1, S + 1, B), jnp.int32)
-    a = decode_attention(q, k, v, lens, block_k=bk)
+    a = decode_attention(q, k, v, lens, block_k=bk, interpret=True)
     b = decode_attention_ref(q, k, v, lens)
     np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
@@ -217,7 +218,7 @@ def test_decode_attention_partial_stats_combine():
         o, m, l = decode_attention(
             q, k[:, :, sl], v[:, :, sl],
             jnp.full((B,), sl.stop - sl.start, jnp.int32),
-            block_k=16, return_stats=True)
+            block_k=16, return_stats=True, interpret=True)
         halves.append((np.asarray(o, np.float64), np.asarray(m, np.float64),
                        np.asarray(l, np.float64)))
     (o1, m1, l1), (o2, m2, l2) = halves

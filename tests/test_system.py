@@ -88,3 +88,27 @@ def test_framework_train_crash_serve(tmp_path):
     assert all(r.done and len(r.out) == 4 for r in reqs)
     # deterministic greedy decode: identical prompts -> identical outputs
     assert reqs[0].out == reqs[1].out == reqs[2].out
+
+
+def test_compile_cache_dir_follows_env_else_repo_default(monkeypatch):
+    """Entry points keep jax's persistent cache where
+    JAX_COMPILATION_CACHE_DIR says (jax reads it itself) and otherwise
+    at the fixed ``.jax_cache/`` in the repository root."""
+    from pathlib import Path
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from repro.launch import cache
+
+    was = jax.config.jax_compilation_cache_dir
+    root = Path(__file__).resolve().parents[1]
+    try:
+        monkeypatch.setenv(cache.ENV_VAR, "/elsewhere/cache")
+        assert cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+
+        monkeypatch.delenv(cache.ENV_VAR)
+        assert cache.enable_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        cc.reset_cache()
