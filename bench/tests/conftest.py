@@ -1,0 +1,9 @@
+"""The benchmark's tests import its harness the way ``bench/run.py`` does."""
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH.parent / "src"), str(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
