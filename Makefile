@@ -1,5 +1,5 @@
 .PHONY: check lint test test-slow test-range api examples docs bench-kernels \
-	bench-mixed bench-range bench-lifecycle bench-index bench-serve bench-wal
+	bench-mixed bench-range bench-lifecycle bench-index bench-wal
 
 check:
 	bash scripts/check.sh
@@ -53,12 +53,6 @@ bench-lifecycle:
 # locate at depth 1 vs multi-level; writes BENCH_index.json
 bench-index:
 	PYTHONPATH=src python -m benchmarks.run --quick --only index
-
-# pipelined serving front end: closed-loop tail-latency matrix
-# (zipf/uniform mixes, p50/p95/p99 per op, saturation throughput vs the
-# synchronous per-request baseline); writes BENCH_serve.json
-bench-serve:
-	PYTHONPATH=src python -m benchmarks.run --quick --only serve
 
 # durability: group-commit WAL ingest vs fsync-per-plan, delta vs full
 # checkpoint bytes (gate: delta <= 25% of full), crash-recovery replay

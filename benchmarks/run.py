@@ -25,11 +25,6 @@ workloads; see each section).  Figures:
                  under delta maintenance vs the flat full-rebuild
                  discipline (4k -> 64k leaves), and locate throughput at
                  depth 1 vs multi-level; writes BENCH_index.json.
-  * serve      — closed-loop tail-latency matrix for the pipelined
-                 admission front end (zipf/uniform x CRUD/range mixes,
-                 per-op p50/p95/p99 + saturation throughput vs the
-                 synchronous per-request baseline, 10k-deep burst
-                 drain); writes BENCH_serve.json.
   * wal        — durability: group-commit WAL ingest vs fsync-per-plan,
                  delta vs full checkpoint bytes + latency, and crash-
                  recovery replay throughput (gated: delta <= 25% of the
@@ -629,16 +624,6 @@ def roofline_summary() -> None:
              f"{r['bottleneck']}-bound;mfu={r['roofline_fraction_mfu']:.3f}")
 
 
-def serve_bench(quick: bool = False,
-                out_path: str = "BENCH_serve.json") -> None:
-    """Closed-loop serving-front-end matrix (DESIGN.md Sec 12): per-op
-    p50/p95/p99 tail latency and saturation throughput, pipelined
-    coalescer vs synchronous per-request baseline, plus the 10k-deep
-    burst drain.  Delegates to ``benchmarks.loadgen``; BENCH_serve.json."""
-    from benchmarks import loadgen
-    loadgen.bench_serve(quick=quick, out_path=out_path)
-
-
 WAL_CFG = UruvConfig(leaf_cap=32, max_leaves=1 << 10, max_versions=1 << 16,
                      max_chain=64)
 WAL_WIDTH = 1024
@@ -803,7 +788,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None,
                     help="fig8|fig9|complexity|kernels|mixed|range|"
-                         "lifecycle|index|serve|wal|roofline")
+                         "lifecycle|index|wal|roofline")
     args = ap.parse_args()
     enable_compile_cache()
     print("name,us_per_call,derived")
@@ -816,7 +801,6 @@ def main() -> None:
         "range": lambda: range_bench(args.quick),
         "lifecycle": lambda: lifecycle_bench(args.quick),
         "index": lambda: index_bench(args.quick),
-        "serve": lambda: serve_bench(args.quick),
         "wal": lambda: wal_bench(args.quick),
         "roofline": roofline_summary,
     }

@@ -38,9 +38,6 @@ python -m benchmarks.run --quick --only lifecycle
 echo "== index: delta maintenance vs flat full-rebuild + locate depth sweep (quick; writes BENCH_index.json) =="
 python -m benchmarks.run --quick --only index
 
-echo "== serve: pipelined front end tail latency vs sync baseline (quick; gates >=2x; writes BENCH_serve.json) =="
-python -m benchmarks.run --quick --only serve
-
 echo "== wal: group-commit vs fsync-per-plan, delta vs full checkpoint,"
 echo "   recovery replay (quick; gates delta<=25% of full + replay>=50kops/s;"
 echo "   writes BENCH_wal.json) =="
@@ -48,9 +45,6 @@ python -m benchmarks.run --quick --only wal
 
 echo "== BENCH_wal.json =="
 cat BENCH_wal.json
-
-echo "== BENCH_serve.json =="
-cat BENCH_serve.json
 
 echo "== BENCH_index.json =="
 cat BENCH_index.json

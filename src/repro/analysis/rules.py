@@ -193,6 +193,7 @@ class LayeringIndexRule(Rule):
 
 HOST_SYNC_METHODS = ("item", "tolist", "block_until_ready")
 HOST_CASTS = ("int", "float", "bool")
+HOST_READ = "host_read"     # repro.obs.host_read: a counted device->host read
 
 
 def _device_pass_static(fn: ast.AST) -> Optional[Tuple[str, ...]]:
@@ -249,7 +250,8 @@ class DevicePassPurityRule(Rule):
     id = "device-pass-purity"
     description = (
         "inside a @device_pass function, host syncs are errors: .item() "
-        "/ .tolist() / block_until_ready / jax.device_get, int()/float()"
+        "/ .tolist() / block_until_ready / jax.device_get / host_read "
+        "(repro.obs, the request path's counted read), int()/float()"
         "/bool() on non-literals, np.asarray/np.array, and Python "
         "if/while on a non-static parameter (a traced value)")
 
@@ -290,9 +292,13 @@ class DevicePassPurityRule(Rule):
                 return f"host sync `.{f.attr}()`"
             if f.attr == "device_get" and _root_name(f) == "jax":
                 return "host sync `jax.device_get`"
+            if f.attr == HOST_READ:
+                return f"host sync `{HOST_READ}()`"
             if (f.attr in ("asarray", "array")
                     and _root_name(f) in ("np", "numpy")):
                 return f"host transfer `np.{f.attr}()`"
+        elif isinstance(f, ast.Name) and f.id == HOST_READ:
+            return f"host sync `{HOST_READ}()`"
         elif isinstance(f, ast.Name) and f.id in HOST_CASTS:
             if node.args and not all(
                     isinstance(a, ast.Constant) for a in node.args):

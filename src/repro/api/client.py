@@ -32,6 +32,7 @@ import numpy as np
 from repro.analysis.marks import device_pass
 from repro.core import store as _store
 from repro.core.ref import KEY_MAX, OP_RANGE
+from repro.obs import host_read, span, timed
 
 from repro.api.executors import (
     LifecyclePolicy, LocalExecutor, RangeOptions, ShardedExecutor,
@@ -161,7 +162,10 @@ class Uruv:
         batches that ran the bounded separator-delta pass) and
         ``index_propagations`` (node updates that propagated above the
         bottom level — the observable O(touched·depth) bound of
-        DESIGN.md Sec 11; sharded stores sum their shards)."""
+        DESIGN.md Sec 11; sharded stores sum their shards), and the host
+        cost of the request path: ``dispatch_ns`` (in ``apply_nowait``),
+        ``device_wait_ns`` (blocked in ``confirm``) and ``host_syncs``
+        (device-to-host reads; DESIGN.md Sec 12, "Tracing")."""
         s = dict(self.executor.stats)
         idx = getattr(self._store, "index", None)
         if idx is not None:
@@ -208,15 +212,17 @@ class Uruv:
         results keep the caller's width, but the clock advances by the
         padded width (NOP slots still occupy announce positions).
         """
-        base = self.ts
-        n = len(batch)
-        if pad_to_pow2 and n:
-            batch = batch.pad_to(pow2_width(n))
-        self._store, values, range_items = self.executor.apply(
-            self._store, batch, light_path=light_path, range_opts=range_opts,
-        )
-        return make_result(values[:n], np.asarray(batch.codes)[:n], base,
-                           range_items)
+        with span("uruv.apply"):
+            base = self.ts
+            n = len(batch)
+            if pad_to_pow2 and n:
+                batch = batch.pad_to(pow2_width(n))
+            self._store, values, range_items = self.executor.apply(
+                self._store, batch, light_path=light_path,
+                range_opts=range_opts,
+            )
+            return make_result(values[:n], np.asarray(batch.codes)[:n], base,
+                               range_items)
 
     # ------------------------------------------------- pipelined (deferred)
     @device_pass(static=("pad_to_pow2", "donate_store"))
@@ -242,26 +248,29 @@ class Uruv:
         n = len(batch)
         if n == 0:
             raise ValueError("apply_nowait requires a non-empty plan")
-        # plan marshalling is host-side BY DESIGN: OpBatch arrays are
-        # numpy before dispatch, so these never sync the device
-        codes = np.asarray(batch.codes)  # uruvlint: disable=device-pass-purity
-        if bool((codes == OP_RANGE).any()):  # uruvlint: disable=device-pass-purity
-            raise ValueError(
-                "apply_nowait is CRUD-only; RANGE plans take apply()")
-        host = OpBatch(codes,  # uruvlint: disable=device-pass-purity
-                       np.asarray(batch.keys),  # uruvlint: disable=device-pass-purity
-                       np.asarray(batch.values))  # uruvlint: disable=device-pass-purity
-        if pad_to_pow2:
-            host = host.pad_to(pow2_width(n))
-        store_before = self._store
-        self._store, values, ok = self.executor.apply_nowait(
-            self._store, host, donate_store=donate_store,
-        )
-        return PendingPlan(
-            batch=host, n_user=n,
-            store_before=None if donate_store else store_before,
-            store_after=self._store, values=values, ok=ok,
-        )
+        # host->device copies, the store pytree and the jitted call's
+        # enqueue: the client's dispatch cost (stats["dispatch_ns"])
+        with timed(self.executor.stats, "dispatch_ns", "uruv.apply_nowait"):
+            # plan marshalling is host-side BY DESIGN: OpBatch arrays are
+            # numpy before dispatch, so these never sync the device
+            codes = np.asarray(batch.codes)  # uruvlint: disable=device-pass-purity
+            if bool((codes == OP_RANGE).any()):  # uruvlint: disable=device-pass-purity
+                raise ValueError(
+                    "apply_nowait is CRUD-only; RANGE plans take apply()")
+            host = OpBatch(codes,  # uruvlint: disable=device-pass-purity
+                           np.asarray(batch.keys),  # uruvlint: disable=device-pass-purity
+                           np.asarray(batch.values))  # uruvlint: disable=device-pass-purity
+            if pad_to_pow2:
+                host = host.pad_to(pow2_width(n))
+            store_before = self._store
+            self._store, values, ok = self.executor.apply_nowait(
+                self._store, host, donate_store=donate_store,
+            )
+            return PendingPlan(
+                batch=host, n_user=n,
+                store_before=None if donate_store else store_before,
+                store_after=self._store, values=values, ok=ok,
+            )
 
     def confirm(self, pending: PendingPlan) -> Optional[Result]:
         """Settle one :meth:`apply_nowait` dispatch (the deferred host
@@ -273,23 +282,31 @@ class Uruv:
         which owns the slow-path and lifecycle machinery and re-derives
         the exact same announce timestamps from the restored clock.
         """
-        if not bool(np.asarray(pending.ok)):
+        stats = self.executor.stats
+        # the pipelined path's one blocking wait: the device finishing the
+        # pass (stats["device_wait_ns"])
+        with timed(stats, "device_wait_ns", "uruv.confirm.wait"):
+            ok = bool(host_read(stats, pending.ok))
+        if not ok:
             self._store = pending.rollback_store()
             return None
-        base = int(np.asarray(pending.store_after.ts)) - len(pending.batch)
-        dur = self.durability
-        if dur is not None:
-            # log-on-confirm (the pipelined half of confirm-after-fsync):
-            # an ACCEPTED plan is logged here, before its Result exists; a
-            # rejected plan is never logged — its replay logs through
-            # apply(), so the WAL carries exactly one record per base_ts
-            dur.log_plan(base, np.asarray(pending.batch.codes),
-                         np.asarray(pending.batch.keys),
-                         np.asarray(pending.batch.values))
-        values = np.asarray(pending.values)[:pending.n_user]
-        return make_result(values,
-                           np.asarray(pending.batch.codes)[:pending.n_user],
-                           base, ())
+        with span("uruv.confirm.fetch"):
+            base = int(host_read(stats, pending.store_after.ts)) \
+                - len(pending.batch)
+            dur = self.durability
+            if dur is not None:
+                # log-on-confirm (the pipelined half of confirm-after-
+                # fsync): an ACCEPTED plan is logged here, before its
+                # Result exists; a rejected plan is never logged — its
+                # replay logs through apply(), so the WAL carries exactly
+                # one record per base_ts
+                dur.log_plan(base, np.asarray(pending.batch.codes),
+                             np.asarray(pending.batch.keys),
+                             np.asarray(pending.batch.values))
+            values = host_read(stats, pending.values)[:pending.n_user]
+            return make_result(
+                values, np.asarray(pending.batch.codes)[:pending.n_user],
+                base, ())
 
     def lifecycle_tick(self) -> None:
         """Run the policy's proactive grow/maintain triggers now.  The

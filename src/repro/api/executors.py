@@ -19,7 +19,9 @@ while the client owns the store value and the ADT surface.  The contract
 
 ``stats`` (shared with the client) counts ``device_passes``,
 ``slow_path_rounds`` and ``compactions`` — the observable wait-free bound
-(benchmarks assert "one device pass per fast-path batch" through it).
+(benchmarks assert "one device pass per fast-path batch" through it) —
+and the client's host cost of the pipelined path: ``dispatch_ns``,
+``device_wait_ns`` and ``host_syncs`` (DESIGN.md Sec 12, "Tracing").
 
 `LocalExecutor` runs on one device via ``repro.core.batch``;
 `ShardedExecutor` runs the same plans over a mesh axis via the
@@ -42,6 +44,7 @@ from repro.core import batch as _batch
 from repro.core import lifecycle as _lifecycle
 from repro.core import sharded as _sharded
 from repro.core import store as _store
+from repro.obs import host_read
 from repro.api.opbatch import OpBatch, RangePage
 
 CapacityError = _batch.CapacityError
@@ -50,7 +53,8 @@ LifecyclePolicy = _lifecycle.LifecyclePolicy
 
 def _new_stats() -> Dict[str, int]:
     return {"device_passes": 0, "slow_path_rounds": 0, "compactions": 0,
-            "grows": 0, "maintain_passes": 0, "leaves_reclaimed": 0}
+            "grows": 0, "maintain_passes": 0, "leaves_reclaimed": 0,
+            "dispatch_ns": 0, "device_wait_ns": 0, "host_syncs": 0}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,7 +104,7 @@ class LocalExecutor:
         return _store.create(self.config)
 
     def ts(self, store) -> int:
-        return int(np.asarray(store.ts))
+        return int(host_read(self.stats, store.ts))
 
     def grow(self, store, *, leaves: bool = False, versions: bool = False,
              tracker: bool = False):
@@ -131,7 +135,7 @@ class LocalExecutor:
     # ----------------------------------------------------------------- write
     def apply(self, store, batch: OpBatch, *, light_path: bool = True,
               range_opts: RangeOptions = RangeOptions()):
-        base = int(np.asarray(store.ts)) \
+        base = int(host_read(self.stats, store.ts)) \
             if self.durability is not None else 0
         store, values, range_pages = _batch.apply_mixed(
             store, batch.codes, batch.keys, batch.values,

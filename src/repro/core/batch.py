@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.core import lifecycle as LC
 from repro.core import store as S
 from repro.core.ref import KEY_MAX, NOT_FOUND, OP_RANGE
+from repro.obs import host_read, span
 
 
 class CapacityError(RuntimeError):
@@ -107,7 +108,9 @@ def _apply_rounds(
     recursion materialises the timestamps once and slices them, so every
     round applies its ops at exactly the timestamps the one-pass
     application would have used.  ``stats`` (see ``repro.api``) counts
-    every device pass and slow-path round.
+    every device pass, slow-path round and host read (``host_syncs``: one
+    per pass on the fast path, the accept flag and the results in one
+    transfer).
 
     Capacity policy (DESIGN.md Sec 10): with a ``policy`` whose
     ``auto_grow`` is set (the ``repro.api`` default), ``OFLOW_LEAVES`` /
@@ -126,10 +129,12 @@ def _apply_rounds(
         store, codes, keys, values, op_ts=op_ts, next_ts=next_ts,
         light_path=light_path, backend=backend,
     )
+    ok, res = host_read(stats, (ok, res))
     if bool(ok):
-        return new_store, np.asarray(res)
+        return new_store, res
     _bump(stats, "slow_path_rounds")
-    reason = int(new_store.oflow) & ~int(store.oflow)
+    new_oflow, old_oflow = host_read(stats, (new_store.oflow, store.oflow))
+    reason = int(new_oflow) & ~int(old_oflow)
     if reason & S.OFLOW_INDEX:
         # fat-node pools fragmented (or root overflow): reindex repacks
         # them at pack_fill — no capacity growth, results unchanged —
@@ -153,10 +158,9 @@ def _apply_rounds(
         compacted, _ = S.compact(_clear_oflow(store))
         # progress check on the actual constrained resources: the version
         # pool and the leaf bump-allocator (compact() resets both)
-        progressed = (
-            int(compacted.n_vers) < int(store.n_vers)
-            or int(compacted.n_alloc) < int(store.n_alloc)
-        )
+        c_vers, s_vers, c_alloc, s_alloc = host_read(stats, (
+            compacted.n_vers, store.n_vers, compacted.n_alloc, store.n_alloc))
+        progressed = int(c_vers) < int(s_vers) or int(c_alloc) < int(s_alloc)
         if not progressed and not (reason & S.OFLOW_LEAFBATCH):
             raise CapacityError(
                 f"store full (versions={int(store.n_vers)}/"
@@ -173,7 +177,7 @@ def _apply_rounds(
         raise CapacityError("single op rejected; leaf_cap too small",
                             store=store, oflow=reason)
     if op_ts is None:
-        base = int(store.ts)
+        base = int(host_read(stats, store.ts))
         op_ts = (base + np.arange(len(keys))).astype(np.int32)
         if next_ts is None:
             next_ts = base + len(keys)
@@ -278,7 +282,7 @@ def apply_mixed(
                 max_rounds=max_rounds, backend=backend, stats=stats,
             )
     if get_ts_fn is None:
-        get_ts_fn = lambda st: int(st.ts)  # noqa: E731
+        get_ts_fn = lambda st: int(host_read(stats, st.ts))  # noqa: E731
     if set_ts_fn is None:
         def set_ts_fn(st, ts):
             return dataclasses.replace(st, ts=jnp.asarray(ts, jnp.int32))
@@ -287,7 +291,8 @@ def apply_mixed(
         return store, np.zeros(0, np.int64), []
     rmask = codes == OP_RANGE
     if not rmask.any():
-        store, res = crud_fn(store, codes, keys, vals, None, None)
+        with span("uruv.segment", kind="crud", width=n):
+            store, res = crud_fn(store, codes, keys, vals, None, None)
         return store, np.asarray(res).astype(np.int64), []
     base = get_ts_fn(store)
     op_ts = (base + np.arange(n)).astype(np.int32)
@@ -299,15 +304,18 @@ def apply_mixed(
         while j < n and bool(rmask[j]) == bool(rmask[i]):
             j += 1
         if rmask[i]:
-            pages = range_all_fn(store, keys[i:j], vals[i:j], op_ts[i:j])
+            with span("uruv.segment", kind="range", width=j - i):
+                pages = range_all_fn(store, keys[i:j], vals[i:j],
+                                     op_ts[i:j])
             results[i:j] = [len(p) for p in pages]
             range_pages.extend(zip(range(i, j), pages))
             # CRUD passes advance the clock themselves (next_ts / the
             # replicated counter); range segments must restate it
             store = set_ts_fn(store, base + j)
         else:
-            store, res = crud_fn(store, codes[i:j], keys[i:j], vals[i:j],
-                                 op_ts[i:j], base + j)
+            with span("uruv.segment", kind="crud", width=j - i):
+                store, res = crud_fn(store, codes[i:j], keys[i:j],
+                                     vals[i:j], op_ts[i:j], base + j)
             results[i:j] = res
         i = j
     return store, results, range_pages
@@ -395,12 +403,9 @@ def bulk_range_all(
         lo_p = np.concatenate([lo, np.full(pad, _DONE_LO, np.int32)])
         hi_p = np.concatenate([hi, np.full(pad, _DONE_HI, np.int32)])
         sn_p = np.concatenate([sn, np.zeros(pad, np.int32)])
-        keys, vals, cnt, trunc, resume = page_fn(store, lo_p, hi_p, sn_p)
-        keys = np.asarray(keys)
-        vals = np.asarray(vals)
-        cnt = np.asarray(cnt)
-        trunc = np.asarray(trunc)[: len(idx)]
-        resume = np.asarray(resume)
+        keys, vals, cnt, trunc, resume = host_read(
+            stats, page_fn(store, lo_p, hi_p, sn_p))
+        trunc = trunc[: len(idx)]
         for a, q in enumerate(idx):
             c = int(cnt[a])
             out[q].extend(zip(keys[a, :c].tolist(), vals[a, :c].tolist()))

@@ -50,6 +50,7 @@ from jax import lax
 from repro.core import index as I
 from repro.core import store as S
 from repro.core.ref import KEY_MAX, TOMBSTONE
+from repro.obs import host_read, span
 
 
 # ---------------------------------------------------------------------------
@@ -153,12 +154,17 @@ def grow(store: S.UruvStore, *, leaves: bool = False, versions: bool = False,
         raise ValueError("grow(): select at least one pool "
                          "(leaves=, versions=, tracker=)")
     cfg = store.cfg
-    return _grow(
-        store,
-        new_ml=next_pool_size(cfg.max_leaves) if leaves else cfg.max_leaves,
-        new_mv=next_pool_size(cfg.max_versions) if versions else cfg.max_versions,
-        new_mt=next_pool_size(cfg.tracker_cap) if tracker else cfg.tracker_cap,
-    )
+    with span("uruv.lifecycle.grow", leaves=leaves, versions=versions,
+              tracker=tracker):
+        return _grow(
+            store,
+            new_ml=next_pool_size(cfg.max_leaves) if leaves
+            else cfg.max_leaves,
+            new_mv=next_pool_size(cfg.max_versions) if versions
+            else cfg.max_versions,
+            new_mt=next_pool_size(cfg.tracker_cap) if tracker
+            else cfg.tracker_cap,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -351,6 +357,7 @@ def _maintain(store: S.UruvStore, phase: jax.Array, *, budget: int):
 
 def maintain(
     store: S.UruvStore, budget: int = 128, *, phase: int = 0,
+    stats: Optional[Dict[str, int]] = None,
 ) -> Tuple[S.UruvStore, int, int]:
     """ONE bounded incremental maintenance pass (device-resident).
 
@@ -368,15 +375,17 @@ def maintain(
     both pair parities of the directory are covered.  A stacked (sharded)
     store dispatches through ``jax.vmap`` — every shard maintains in the
     same call, so shard shapes stay equal (the replicated-decision rule).
+    ``stats`` counts the pass's one host read (``host_syncs``).
     """
     ph = jnp.asarray(phase % 2, jnp.int32)
-    if np.asarray(store.ts).ndim:          # stacked (sharded) store
+    if np.ndim(store.ts):                  # stacked (sharded) store
         fn = jax.vmap(functools.partial(_maintain, budget=budget),
                       in_axes=(0, None))
         new, reclaimed, merged = fn(store, ph)
     else:
         new, reclaimed, merged = _maintain(store, ph, budget=budget)
-    return new, int(np.asarray(reclaimed).sum()), int(np.asarray(merged).sum())
+    reclaimed, merged = host_read(stats, (reclaimed, merged))
+    return new, int(np.sum(reclaimed)), int(np.sum(merged))
 
 
 # ---------------------------------------------------------------------------
@@ -473,20 +482,22 @@ def leaf_accounting(store: S.UruvStore) -> Dict[str, int]:
     }
 
 
-def live_key_count(store: S.UruvStore) -> int:
+def live_key_count(store: S.UruvStore,
+                   stats: Optional[Dict[str, int]] = None) -> int:
     """Total keys held by index-referenced leaves (host-side; frozen
     leavings keep stale counts and are excluded).  Tombstoned keys count
     until maintenance purges them — this is a pool-occupancy figure, not
     a liveness oracle."""
-    lc = np.asarray(store.leaf_count)
-    ref = np.asarray(store.index.leaf_ent) >= 0   # same shape, incl. stacked
-    return int(lc[ref].sum())
+    lc, ent = host_read(stats, (store.leaf_count, store.index.leaf_ent))
+    return int(lc[ent >= 0].sum())   # same shape, incl. stacked
 
 
-def dead_fraction(store: S.UruvStore) -> float:
+def dead_fraction(store: S.UruvStore,
+                  stats: Optional[Dict[str, int]] = None) -> float:
     """Dead (unreferenced-but-allocated) fraction of the leaf pool."""
-    n_alloc = int(np.asarray(store.n_alloc).sum())
-    live = int(np.asarray(store.n_leaves).sum())
+    n_alloc, n_leaves = host_read(stats, (store.n_alloc, store.n_leaves))
+    n_alloc = int(np.sum(n_alloc))
+    live = int(np.sum(n_leaves))
     return (n_alloc - live) / max(n_alloc, 1)
 
 
@@ -505,21 +516,22 @@ def run_maintenance(
     """
     if maintain_fn is None:
         def maintain_fn(st, budget, phase):
-            return maintain(st, budget, phase=phase)
+            return maintain(st, budget, phase=phase, stats=stats)
     passes = max_passes if max_passes is not None else policy.maintain_passes
-    for p in range(passes):
-        store, reclaimed, merged = maintain_fn(
-            store, policy.maintain_budget, p % 2
-        )
-        if stats is not None:
-            stats["maintain_passes"] = stats.get("maintain_passes", 0) + 1
-            stats["leaves_reclaimed"] = (
-                stats.get("leaves_reclaimed", 0) + reclaimed
+    with span("uruv.lifecycle.maintain", max_passes=passes):
+        for p in range(passes):
+            store, reclaimed, merged = maintain_fn(
+                store, policy.maintain_budget, p % 2
             )
-        if reclaimed == 0 and merged == 0:
-            break
-        if dead_fraction(store) < policy.frozen_trigger / 2:
-            break
+            if stats is not None:
+                stats["maintain_passes"] = stats.get("maintain_passes", 0) + 1
+                stats["leaves_reclaimed"] = (
+                    stats.get("leaves_reclaimed", 0) + reclaimed
+                )
+            if reclaimed == 0 and merged == 0:
+                break
+            if dead_fraction(store, stats) < policy.frozen_trigger / 2:
+                break
     return store
 
 
@@ -539,25 +551,26 @@ def lifecycle_tick(
     """
     if not (policy.auto_grow or policy.auto_maintain):
         return store
-    n_alloc_raw, n_leaves_raw = jax.device_get(
-        (store.n_alloc, store.n_leaves))
-    n_alloc = int(np.asarray(n_alloc_raw).sum())
-    dead = n_alloc - int(np.asarray(n_leaves_raw).sum())
-    if (policy.auto_maintain and dead >= policy.min_dead_leaves
-            and dead / max(n_alloc, 1) >= policy.frozen_trigger):
-        store = run_maintenance(store, policy, stats=stats,
-                                maintain_fn=maintain_fn)
-        n_alloc_raw = jax.device_get(store.n_alloc)
-    if (policy.auto_grow
-            and int(np.asarray(n_alloc_raw).max())
-            > policy.grow_occupancy * store.cfg.max_leaves):
-        if grow_fn is None:
-            if stats is not None:
-                stats["grows"] = stats.get("grows", 0) + 1
-            store = grow(store, leaves=True)
-        else:
-            store = grow_fn(store)
-    return store
+    with span("uruv.lifecycle.tick"):
+        n_alloc_raw, n_leaves_raw = host_read(
+            stats, (store.n_alloc, store.n_leaves))
+        n_alloc = int(np.asarray(n_alloc_raw).sum())
+        dead = n_alloc - int(np.asarray(n_leaves_raw).sum())
+        if (policy.auto_maintain and dead >= policy.min_dead_leaves
+                and dead / max(n_alloc, 1) >= policy.frozen_trigger):
+            store = run_maintenance(store, policy, stats=stats,
+                                    maintain_fn=maintain_fn)
+            n_alloc_raw = host_read(stats, store.n_alloc)
+        if (policy.auto_grow
+                and int(np.asarray(n_alloc_raw).max())
+                > policy.grow_occupancy * store.cfg.max_leaves):
+            if grow_fn is None:
+                if stats is not None:
+                    stats["grows"] = stats.get("grows", 0) + 1
+                store = grow(store, leaves=True)
+            else:
+                store = grow_fn(store)
+        return store
 
 
 def relieve_pressure(
@@ -582,35 +595,36 @@ def relieve_pressure(
             stats["reindexes"] = stats.get("reindexes", 0) + 1
         store = S.reindex(store)
     if reason & S.OFLOW_LEAVES:
-        before = int(np.asarray(store.n_alloc).sum())
-        if dead_fraction(store) >= policy.frozen_trigger:
+        before = int(np.sum(host_read(stats, store.n_alloc)))
+        if dead_fraction(store, stats) >= policy.frozen_trigger:
             store = run_maintenance(
                 store, policy, stats=stats,
                 max_passes=policy.pressure_passes,
             )
-        if int(np.asarray(store.n_alloc).sum()) >= before:
+        if int(np.sum(host_read(stats, store.n_alloc))) >= before:
             store = grow(store, leaves=True)
             if stats is not None:
                 stats["grows"] = stats.get("grows", 0) + 1
     if reason & S.OFLOW_VERSIONS:
         cfg = store.cfg
-        n_vers = int(np.asarray(store.n_vers).max())
+        n_vers_all = host_read(stats, store.n_vers)
+        n_vers = int(np.max(n_vers_all))
         # compact() can reclaim at most sum(n_vers) - live_keys versions
         # (every key that survives retains >= 1): pure-ingest pools with
         # no version history have nothing to give back — skip the
         # stop-the-world pass and grow directly.
         reclaimable_bound = (
-            int(np.asarray(store.n_vers).sum()) - live_key_count(store)
+            int(np.sum(n_vers_all)) - live_key_count(store, stats)
         )
         if (reclaimable_bound >= width
                 and n_vers >= policy.version_gc_fraction * cfg.max_versions):
             if stats is not None:
                 stats["compactions"] = stats.get("compactions", 0) + 1
-            if np.asarray(store.ts).ndim:          # stacked (sharded) store
+            if np.ndim(store.ts):                  # stacked (sharded) store
                 store, _ = jax.vmap(S.compact)(store)
             else:
                 store, _ = S.compact(store)
-            n_vers = int(np.asarray(store.n_vers).max())
+            n_vers = int(np.max(host_read(stats, store.n_vers)))
         while n_vers + width > store.cfg.max_versions:
             store = grow(store, versions=True)
             if stats is not None:

@@ -39,6 +39,12 @@ steps instead of one conflicted pass.
 RANGE-bearing requests coalesce too, but their plans execute through the
 synchronous ``apply`` (their pagination loop is host-driven); the
 coalescer drains the pipeline first so linearization order stays FIFO.
+
+Every plan's host cycle is measured (DESIGN.md Sec 12, "Tracing"): spans
+``uruv.coalescer.build`` / ``.retire`` / ``.materialize`` / ``.replay``
+carry ``plan=<plan number>``, and ``stats`` sums ``build_ns``,
+``materialize_ns`` and ``queue_wait_ns`` (submit to leaving the queue,
+over ``requests_dispatched`` requests).
 """
 
 from __future__ import annotations
@@ -52,6 +58,7 @@ import numpy as np
 
 from repro.analysis.marks import device_pass
 from repro.api import OP_NOP, OpBatch, Result, Uruv
+from repro.obs import span, timed
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,8 +92,7 @@ class OpFuture:
     dispatched and settled, then returns the per-request :class:`Result`
     (values / found / per-op timestamps / complete range pages, announce
     positions rebased to the request).  ``submit_t``/``done_t`` (host
-    monotonic clock) bracket queueing + batching + execution — the
-    tail-latency harness reads per-op latency off them.
+    monotonic clock) bracket queueing + batching + execution.
     """
 
     __slots__ = ("_coalescer", "n_ops", "submit_t", "done_t", "_result")
@@ -125,6 +131,7 @@ class _Queued:
 class _InFlight:
     pending: object        # api.PendingPlan
     spans: List[Tuple[OpFuture, int, int]]
+    plan_no: int           # the plan's number (stats["plans"] at build)
 
 
 class Coalescer:
@@ -160,6 +167,10 @@ class Coalescer:
             "requests": 0, "ops": 0, "plans": 0, "plans_sync": 0,
             "plans_rejected": 0, "replays": 0, "padded_ops": 0,
             "max_queue_depth": 0, "hot_segments": 0,
+            # host cycle of the plans (ns); keys distinct from the
+            # executor's stats, which readers merge with these
+            "build_ns": 0, "materialize_ns": 0, "queue_wait_ns": 0,
+            "requests_dispatched": 0,
         }
 
     # -------------------------------------------------------------- admission
@@ -219,8 +230,15 @@ class Coalescer:
             force or self._queued_ops >= width
             or now - self.queue[0].future.submit_t >= self._deadline_s()
         ):
-            reqs = self._take(width)
-            self._dispatch(reqs)
+            plan_no = self.stats["plans"]
+            with timed(self.stats, "build_ns", "uruv.coalescer.build",
+                       plan=plan_no):
+                reqs = self._take(width)
+                plan, spans, has_range = self._build(reqs)
+                # the taken requests and their one-op batches are freed
+                # here, a cost of the plan's build
+                del reqs
+            self._dispatch(plan, spans, has_range, plan_no)
             return True
         if force and self.inflight:
             self._retire_oldest()
@@ -250,23 +268,35 @@ class Coalescer:
         self._queued_ops -= total
         return take
 
-    # -------------------------------------------------------------- dispatch
-    @device_pass(static=("reqs",))  # reqs is host metadata (futures + spans)
-    def _dispatch(self, reqs: List[_Queued]) -> None:
+    def _build(self, reqs: List[_Queued]):
+        """The plan of the taken requests (padded to a power of two), each
+        request's slice of it and whether any carries a RANGE; sums the
+        requests' queue wait, from submit to the start of this build."""
         spans: List[Tuple[OpFuture, int, int]] = []
         at = 0
+        taken = time.monotonic()
+        wait = 0.0
         for q in reqs:
-            spans.append((q.future, at, at + q.future.n_ops))
-            at += q.future.n_ops
+            fut = q.future
+            spans.append((fut, at, at + fut.n_ops))
+            at += fut.n_ops
+            wait += taken - fut.submit_t
+        self.stats["queue_wait_ns"] += int(wait * 1e9)
+        self.stats["requests_dispatched"] += len(reqs)
         plan = OpBatch.concat(*[q.plan for q in reqs]).pad_to_pow2()
         self.stats["plans"] += 1
         self.stats["padded_ops"] += len(plan) - at
-        # plan arrays are host numpy (built by OpBatch on the host);
-        # probing them costs no device sync
-        self._note_skew(np.asarray(plan.keys), np.asarray(plan.codes))  # uruvlint: disable=device-pass-purity
+        # plan arrays are host numpy (built by OpBatch on the host)
+        self._note_skew(np.asarray(plan.keys), np.asarray(plan.codes))
         if self.dispatch_log is not None:
             self.dispatch_log.append((plan, spans))
-        if not (any(q.has_range for q in reqs) or not self._pipelined):
+        return plan, spans, any(q.has_range for q in reqs)
+
+    # -------------------------------------------------------------- dispatch
+    @device_pass(static=("has_range", "plan_no"))  # host flags / counter
+    def _dispatch(self, plan: OpBatch, spans: List[Tuple[OpFuture, int, int]],
+                  has_range: bool, plan_no: int) -> None:
+        if not (has_range or not self._pipelined):
             while len(self.inflight) >= self._depth:
                 self._retire_oldest()
             try:
@@ -275,57 +305,63 @@ class Coalescer:
             except NotImplementedError:
                 self._pipelined = False
             else:
-                self.inflight.append(_InFlight(pending, spans))
+                self.inflight.append(_InFlight(pending, spans, plan_no))
                 return
         # host-driven pagination (RANGE) or a sync-only executor: drain
         # the pipeline (FIFO order), then one coalesced synchronous plan
         while self.inflight:
             self._retire_oldest()
         self.stats["plans_sync"] += 1
-        self._materialize(spans, self.db.apply(plan))
+        self._materialize(spans, self.db.apply(plan), plan_no)
         self._adapt(rejected=False)
 
     def _retire_oldest(self) -> None:
         entry = self.inflight.popleft()
-        res = self.db.confirm(entry.pending)
-        if res is not None:
-            self._materialize(entry.spans, res)
-            self._adapt(rejected=False)
-            return
-        # atomic rejection: the client rolled back to the pre-plan store;
-        # every unconfirmed plan behind it ran on speculative state and is
-        # invalid too — replay all of them synchronously, in order, at the
-        # timestamps the restored clock re-derives (bit-exact)
-        self.stats["plans_rejected"] += 1
-        self._adapt(rejected=True)
-        replay = [entry] + list(self.inflight)
-        self.inflight.clear()
-        for e in replay:
-            self.stats["replays"] += 1
-            self._materialize(e.spans, self.db.apply(e.pending.batch))
+        with span("uruv.coalescer.retire", plan=entry.plan_no):
+            res = self.db.confirm(entry.pending)
+            if res is not None:
+                self._materialize(entry.spans, res, entry.plan_no)
+                self._adapt(rejected=False)
+                return
+            # atomic rejection: the client rolled back to the pre-plan
+            # store; every unconfirmed plan behind it ran on speculative
+            # state and is invalid too — replay all of them synchronously,
+            # in order, at the timestamps the restored clock re-derives
+            # (bit-exact)
+            self.stats["plans_rejected"] += 1
+            self._adapt(rejected=True)
+            replay = [entry] + list(self.inflight)
+            self.inflight.clear()
+            with span("uruv.coalescer.replay", plan=entry.plan_no):
+                for e in replay:
+                    self.stats["replays"] += 1
+                    self._materialize(e.spans, self.db.apply(e.pending.batch),
+                                      e.plan_no)
 
     # ------------------------------------------------------------- futures
-    def _materialize(self, spans, res: Result) -> None:
+    def _materialize(self, spans, res: Result, plan_no: int) -> None:
         """Slice the batched Result into per-request Results and resolve
         the futures.  Every field keeps the batch's values verbatim —
         only announce positions (range_index) rebase to the request."""
-        values = np.asarray(res.values)
-        found = np.asarray(res.found)
-        ts = np.asarray(res.timestamps)
-        rng_pos = np.asarray(res.range_index).tolist()
-        rng_resume = np.asarray(res.range_resume)
-        for fut, a, b in spans:
-            idx, pages, resumes = [], [], []
-            for j, pos in enumerate(rng_pos):
-                if a <= pos < b:
-                    idx.append(pos - a)
-                    pages.append(res.range_pages[j])
-                    resumes.append(int(rng_resume[j]))
-            fut._resolve(Result(
-                values=values[a:b],
-                found=found[a:b],
-                timestamps=ts[a:b],
-                range_index=np.asarray(idx, np.int32),
-                range_pages=tuple(pages),
-                range_resume=np.asarray(resumes, np.int32),
-            ))
+        with timed(self.stats, "materialize_ns", "uruv.coalescer.materialize",
+                   plan=plan_no):
+            values = np.asarray(res.values)
+            found = np.asarray(res.found)
+            ts = np.asarray(res.timestamps)
+            rng_pos = np.asarray(res.range_index).tolist()
+            rng_resume = np.asarray(res.range_resume)
+            for fut, a, b in spans:
+                idx, pages, resumes = [], [], []
+                for j, pos in enumerate(rng_pos):
+                    if a <= pos < b:
+                        idx.append(pos - a)
+                        pages.append(res.range_pages[j])
+                        resumes.append(int(rng_resume[j]))
+                fut._resolve(Result(
+                    values=values[a:b],
+                    found=found[a:b],
+                    timestamps=ts[a:b],
+                    range_index=np.asarray(idx, np.int32),
+                    range_pages=tuple(pages),
+                    range_resume=np.asarray(resumes, np.int32),
+                ))

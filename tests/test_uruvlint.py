@@ -136,6 +136,24 @@ def test_purity_bad_fires():
     assert "block_until_ready" in msgs and "`if`" in msgs
 
 
+def test_purity_host_read_fires():
+    """``repro.obs.host_read`` is the request path's counted device read:
+    a host sync, whether imported by name or through its module."""
+    fs = lint(DevicePassPurityRule(), ("src/repro/api/hot.py", """
+        from repro import obs
+        from repro.obs import host_read
+        from repro.analysis.marks import device_pass
+
+        @device_pass
+        def hot(self, pending):
+            ok = host_read(self.stats, pending.ok)
+            ts = obs.host_read(self.stats, pending.ts)
+            return ok, ts
+    """))
+    assert rule_ids(fs) == ["device-pass-purity"] and len(fs) == 2
+    assert all("host_read()" in f.message for f in fs)
+
+
 def test_purity_good_passes():
     assert lint(DevicePassPurityRule(), ("src/repro/core/hot.py", """
         import jax.numpy as jnp
