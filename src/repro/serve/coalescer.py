@@ -23,7 +23,13 @@ with the host and the device overlapped:
 Each client gets an :class:`OpFuture` that slices its ops out of the
 batched result: values, found mask, per-op linearization timestamps, and
 complete range pages are bit-exact with issuing the same coalesced plans
-synchronously (property-tested).
+synchronously (property-tested).  The coalescer keeps requests per plan,
+in columns: a queued request is a ``(future, plan)`` tuple, a plan's
+build concatenates each column once, and a plan's futures resolve in
+one pass: a running count of RANGE positions gives each request its
+RANGE entries, a request with none shares read-only empty range fields,
+and every future of the plan gets one ``done_t``
+(``stats["plans_range_free"]`` counts the plans with no RANGE op).
 
 Coalescing is SKEW-AWARE (contention-adapting trees, arXiv:1709.00722):
 zipfian hot-key traffic is exactly where a fixed batch width/deadline
@@ -51,13 +57,14 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import time
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.analysis.marks import device_pass
-from repro.api import OP_NOP, OpBatch, Result, Uruv
+from repro.api import OP_NOP, OP_RANGE, OpBatch, Result, Uruv
 from repro.obs import span, timed
 
 
@@ -115,22 +122,21 @@ class OpFuture:
                     "coalescer made no progress with futures outstanding")
         return self._result
 
-    def _resolve(self, result: Result) -> None:
-        self._result = result
-        self.done_t = time.monotonic()
 
+# A queued request: its future and its plan (host arrays, builder-validated).
+_Queued = Tuple[OpFuture, OpBatch]
 
-@dataclasses.dataclass
-class _Queued:
-    future: OpFuture
-    plan: OpBatch          # host arrays, builder-validated
-    has_range: bool
+# the range fields of every request with no RANGE op: shared, read-only,
+# zero-length
+_NO_RANGE = np.zeros(0, np.int32)
+_NO_RANGE.flags.writeable = False
 
 
 @dataclasses.dataclass
 class _InFlight:
     pending: object        # api.PendingPlan
-    spans: List[Tuple[OpFuture, int, int]]
+    futs: Sequence[OpFuture]
+    bounds: Sequence[int]  # request i's ops are plan[bounds[i]:bounds[i + 1]]
     plan_no: int           # the plan's number (stats["plans"] at build)
 
 
@@ -171,23 +177,25 @@ class Coalescer:
             # executor's stats, which readers merge with these
             "build_ns": 0, "materialize_ns": 0, "queue_wait_ns": 0,
             "requests_dispatched": 0,
+            # plans whose futures took the range-free materialize path
+            "plans_range_free": 0,
         }
 
     # -------------------------------------------------------------- admission
     def submit(self, plan: OpBatch) -> OpFuture:
         """Admit one client request (an already-built `OpBatch`) and
         return its future.  Ops keep FIFO announce order across requests."""
-        n = len(plan)
+        n = plan.codes.shape[0]           # len(plan), without the call
         if n == 0:
             raise ValueError("empty request")
         fut = OpFuture(self, n)
-        has_range = bool(plan.range_positions.size)
-        self.queue.append(_Queued(fut, plan, has_range))
+        queue, stats = self.queue, self.stats
+        queue.append((fut, plan))
         self._queued_ops += n
-        self.stats["requests"] += 1
-        self.stats["ops"] += n
-        self.stats["max_queue_depth"] = max(self.stats["max_queue_depth"],
-                                            len(self.queue))
+        stats["requests"] += 1
+        stats["ops"] += n
+        if len(queue) > stats["max_queue_depth"]:
+            stats["max_queue_depth"] = len(queue)
         return fut
 
     # --------------------------------------------------------------- policy
@@ -228,17 +236,17 @@ class Coalescer:
         width = self._effective_width()
         if self.queue and (
             force or self._queued_ops >= width
-            or now - self.queue[0].future.submit_t >= self._deadline_s()
+            or now - self.queue[0][0].submit_t >= self._deadline_s()
         ):
             plan_no = self.stats["plans"]
             with timed(self.stats, "build_ns", "uruv.coalescer.build",
                        plan=plan_no):
                 reqs = self._take(width)
-                plan, spans, has_range = self._build(reqs)
+                plan, futs, bounds, has_range = self._build(reqs)
                 # the taken requests and their one-op batches are freed
                 # here, a cost of the plan's build
                 del reqs
-            self._dispatch(plan, spans, has_range, plan_no)
+            self._dispatch(plan, futs, bounds, has_range, plan_no)
             return True
         if force and self.inflight:
             self._retire_oldest()
@@ -259,43 +267,47 @@ class Coalescer:
         self.db.lifecycle_tick()
 
     def _take(self, width: int) -> List[_Queued]:
-        take = [self.queue.popleft()]
-        total = take[0].future.n_ops
-        while self.queue and total + self.queue[0].future.n_ops <= width:
-            q = self.queue.popleft()
+        queue = self.queue
+        take = [queue.popleft()]
+        total = take[0][0].n_ops
+        while queue and total + queue[0][0].n_ops <= width:
+            q = queue.popleft()
             take.append(q)
-            total += q.future.n_ops
+            total += q[0].n_ops
         self._queued_ops -= total
         return take
 
     def _build(self, reqs: List[_Queued]):
-        """The plan of the taken requests (padded to a power of two), each
-        request's slice of it and whether any carries a RANGE; sums the
-        requests' queue wait, from submit to the start of this build."""
-        spans: List[Tuple[OpFuture, int, int]] = []
-        at = 0
+        """The plan of the taken requests (padded to a power of two), their
+        futures, the bounds of each request's slice of the plan, and
+        whether the plan carries a RANGE; sums the requests' queue wait,
+        from submit to the start of this build."""
+        futs, plans = zip(*reqs)
         taken = time.monotonic()
-        wait = 0.0
-        for q in reqs:
-            fut = q.future
-            spans.append((fut, at, at + fut.n_ops))
-            at += fut.n_ops
-            wait += taken - fut.submit_t
-        self.stats["queue_wait_ns"] += int(wait * 1e9)
-        self.stats["requests_dispatched"] += len(reqs)
-        plan = OpBatch.concat(*[q.plan for q in reqs]).pad_to_pow2()
+        k = len(futs)
+        submit_t = np.fromiter((f.submit_t for f in futs), np.float64, k)
+        self.stats["queue_wait_ns"] += int((taken - submit_t).sum() * 1e9)
+        self.stats["requests_dispatched"] += k
+        # plan arrays are host numpy (built by OpBatch on the host)
+        codes = np.concatenate([p.codes for p in plans])
+        keys = np.concatenate([p.keys for p in plans])
+        at = len(codes)
+        plan = OpBatch(codes, keys, np.concatenate([p.values for p in plans])
+                       ).pad_to_pow2()
+        bounds = [0, *itertools.accumulate(f.n_ops for f in futs)]
         self.stats["plans"] += 1
         self.stats["padded_ops"] += len(plan) - at
-        # plan arrays are host numpy (built by OpBatch on the host)
-        self._note_skew(np.asarray(plan.keys), np.asarray(plan.codes))
+        self._note_skew(keys, codes)
         if self.dispatch_log is not None:
-            self.dispatch_log.append((plan, spans))
-        return plan, spans, any(q.has_range for q in reqs)
+            self.dispatch_log.append(
+                (plan, list(zip(futs, bounds, bounds[1:]))))
+        return plan, futs, bounds, bool((codes == OP_RANGE).any())
 
     # -------------------------------------------------------------- dispatch
     @device_pass(static=("has_range", "plan_no"))  # host flags / counter
-    def _dispatch(self, plan: OpBatch, spans: List[Tuple[OpFuture, int, int]],
-                  has_range: bool, plan_no: int) -> None:
+    def _dispatch(self, plan: OpBatch, futs: Sequence[OpFuture],
+                  bounds: Sequence[int], has_range: bool,
+                  plan_no: int) -> None:
         if not (has_range or not self._pipelined):
             while len(self.inflight) >= self._depth:
                 self._retire_oldest()
@@ -305,14 +317,14 @@ class Coalescer:
             except NotImplementedError:
                 self._pipelined = False
             else:
-                self.inflight.append(_InFlight(pending, spans, plan_no))
+                self.inflight.append(_InFlight(pending, futs, bounds, plan_no))
                 return
         # host-driven pagination (RANGE) or a sync-only executor: drain
         # the pipeline (FIFO order), then one coalesced synchronous plan
         while self.inflight:
             self._retire_oldest()
         self.stats["plans_sync"] += 1
-        self._materialize(spans, self.db.apply(plan), plan_no)
+        self._materialize(futs, bounds, self.db.apply(plan), plan_no)
         self._adapt(rejected=False)
 
     def _retire_oldest(self) -> None:
@@ -320,7 +332,8 @@ class Coalescer:
         with span("uruv.coalescer.retire", plan=entry.plan_no):
             res = self.db.confirm(entry.pending)
             if res is not None:
-                self._materialize(entry.spans, res, entry.plan_no)
+                self._materialize(entry.futs, entry.bounds, res,
+                                  entry.plan_no)
                 self._adapt(rejected=False)
                 return
             # atomic rejection: the client rolled back to the pre-plan
@@ -335,33 +348,48 @@ class Coalescer:
             with span("uruv.coalescer.replay", plan=entry.plan_no):
                 for e in replay:
                     self.stats["replays"] += 1
-                    self._materialize(e.spans, self.db.apply(e.pending.batch),
+                    self._materialize(e.futs, e.bounds,
+                                      self.db.apply(e.pending.batch),
                                       e.plan_no)
 
     # ------------------------------------------------------------- futures
-    def _materialize(self, spans, res: Result, plan_no: int) -> None:
+    def _materialize(self, futs: Sequence[OpFuture], bounds: Sequence[int],
+                     res: Result, plan_no: int) -> None:
         """Slice the batched Result into per-request Results and resolve
         the futures.  Every field keeps the batch's values verbatim —
-        only announce positions (range_index) rebase to the request."""
+        only announce positions (range_index) rebase to the request.  A
+        request with no RANGE op gets the shared read-only empty range
+        fields; every future of the plan gets one ``done_t``, read after
+        the last of them is resolved."""
         with timed(self.stats, "materialize_ns", "uruv.coalescer.materialize",
                    plan=plan_no):
             values = np.asarray(res.values)
             found = np.asarray(res.found)
             ts = np.asarray(res.timestamps)
-            rng_pos = np.asarray(res.range_index).tolist()
+            rng_index = np.asarray(res.range_index)
             rng_resume = np.asarray(res.range_resume)
-            for fut, a, b in spans:
-                idx, pages, resumes = [], [], []
-                for j, pos in enumerate(rng_pos):
-                    if a <= pos < b:
-                        idx.append(pos - a)
-                        pages.append(res.range_pages[j])
-                        resumes.append(int(rng_resume[j]))
-                fut._resolve(Result(
-                    values=values[a:b],
-                    found=found[a:b],
-                    timestamps=ts[a:b],
-                    range_index=np.asarray(idx, np.int32),
-                    range_pages=tuple(pages),
-                    range_resume=np.asarray(resumes, np.int32),
-                ))
+            pages = res.range_pages
+            if not rng_index.size:
+                self.stats["plans_range_free"] += 1
+            # range_index is in announce order, so request i owns the RANGE
+            # entries cut[i]:cut[i + 1], cut[i] = # of them before bounds[i]
+            before = np.zeros(len(values) + 1, np.int64)
+            np.cumsum(np.bincount(rng_index, minlength=len(values)),
+                      out=before[1:])
+            cut = before[bounds].tolist()
+            for fut, a, b, lo, hi in zip(futs, bounds, bounds[1:],
+                                         cut, cut[1:]):
+                # positional, in field order: values, found, timestamps,
+                # range_index, range_pages, range_resume
+                if lo == hi:
+                    fut._result = Result(values[a:b], found[a:b], ts[a:b],
+                                         _NO_RANGE, (), _NO_RANGE)
+                else:
+                    fut._result = Result(
+                        values[a:b], found[a:b], ts[a:b],
+                        (rng_index[lo:hi] - a).astype(np.int32),
+                        tuple(pages[lo:hi]),
+                        rng_resume[lo:hi].astype(np.int32))
+            done_t = time.monotonic()
+            for fut in futs:
+                fut.done_t = done_t

@@ -70,7 +70,7 @@ def _record_builds(co):
     def recording(reqs):
         wait0, t0 = co.stats["queue_wait_ns"], time.monotonic()
         out = build(reqs)
-        builds.append((t0, time.monotonic(), [q.future for q in reqs],
+        builds.append((t0, time.monotonic(), [fut for fut, _ in reqs],
                        co.stats["queue_wait_ns"] - wait0))
         return out
 
@@ -107,6 +107,42 @@ def test_counters_account_for_every_plan_and_request():
         assert t0 - 1e-6 <= taken <= t1 + 1e-6
         for f in fs:
             assert f.submit_t <= t0 and t1 <= f.done_t
+
+
+def test_queue_counters_match_a_per_request_count():
+    """``requests``, ``ops``, ``max_queue_depth`` and ``requests_dispatched``
+    against a count kept request by request, over waves of one-op and
+    multi-op requests that sometimes fit the plan whole and sometimes do
+    not (the greedy take)."""
+    db, co = _loaded_coalescer()
+    rng = np.random.default_rng(11)
+    taken, take = [], co._take
+
+    def counting(width):
+        reqs = take(width)
+        taken.append(sum(fut.n_ops for fut, _ in reqs))
+        assert taken[-1] <= width or len(reqs) == 1
+        requests[0] += len(reqs)
+        return reqs
+
+    co._take = counting
+    requests = [0]               # requests taken off the queue so far
+    c0 = dict(co.stats)
+    n_req = n_ops = depth = 0
+    for wave in range(8):
+        for _ in range(int(rng.integers(50, 3 * WIDTH))):
+            n = 1 if rng.random() < 0.7 else int(rng.integers(2, 9))
+            co.submit(OpBatch.searches(rng.choice(KEYS, n)))
+            n_req, n_ops = n_req + 1, n_ops + n
+            depth = max(depth, n_req - requests[0])
+        for _ in range(int(rng.integers(0, 4))):
+            co.pump(force=True)
+    co.flush()
+    c = _delta(co.stats, c0)
+    assert c["requests"] == c["requests_dispatched"] == n_req == requests[0]
+    assert c["ops"] == n_ops == sum(taken)
+    assert co.stats["max_queue_depth"] == depth
+    assert c["plans"] == len(taken) and len(co.queue) == 0
 
 
 def test_counter_keys_exist_from_construction_and_do_not_collide():

@@ -10,12 +10,13 @@ replay, and future slicing must all be invisible in results.
 """
 
 import collections
+import time
 
 import numpy as np
 import pytest
 
 from repro.api import (
-    KEY_MAX, NOT_FOUND, OpBatch, Uruv, UruvConfig,
+    KEY_MAX, NOT_FOUND, OP_RANGE, OpBatch, Uruv, UruvConfig,
 )
 from repro.serve.coalescer import AdmissionPolicy, Coalescer, OpFuture
 from repro.serve.engine import prefix_hash
@@ -305,3 +306,131 @@ def test_coalescer_adapts_width_on_rejection():
 
     of = OpFuture(c2, 1)
     assert not of.done
+
+
+def _request_stream(rng, n):
+    """``n`` range-free requests of one to four ops over a few hundred
+    keys, with one 32-key insert run that a leaf of 8 cannot take in a
+    fast-path pass (so a pipelined plan is rejected and replayed)."""
+    reqs = []
+    for i in range(n):
+        if i == n // 3:
+            keys = np.arange(3000, 3032, dtype=np.int32)
+            reqs.append(OpBatch.inserts(keys, keys % 13 + 1))
+            continue
+        parts = []
+        for _ in range(int(rng.integers(1, 5)) if rng.random() < 0.4 else 1):
+            k, r = int(rng.integers(1, 400)), rng.random()
+            parts.append(OpBatch.inserts([k], [k % 89 + 1]) if r < 0.4
+                         else OpBatch.deletes([k]) if r < 0.55
+                         else OpBatch.searches([k]))
+        reqs.append(OpBatch.concat(*parts))
+    return reqs
+
+
+def _expected_results(dispatch_log, cfg, prefill):
+    """Each request's Result, sliced the general way from ``Uruv.apply``
+    of the coalesced plans, in order, on a fresh client."""
+    db = Uruv(cfg)
+    db.apply(prefill)
+    want = {}
+    for plan, spans in dispatch_log:
+        res = db.apply(plan)
+        pos = np.asarray(res.range_index).tolist()
+        for fut, a, b in spans:
+            mine = [j for j, p in enumerate(pos) if a <= p < b]
+            want[id(fut)] = (
+                np.asarray(res.values)[a:b], np.asarray(res.found)[a:b],
+                np.asarray(res.timestamps)[a:b],
+                np.asarray([pos[j] - a for j in mine], np.int32),
+                [np.asarray(res.range_pages[j]) for j in mine],
+                np.asarray([int(np.asarray(res.range_resume)[j])
+                            for j in mine], np.int32))
+    return want
+
+
+@pytest.mark.parametrize("with_ranges", [False, True])
+def test_range_free_and_general_materialize_match_apply(with_ranges):
+    """One stream of one-op and multi-op requests, served range-free (no
+    plan carries a RANGE op, a rejected-plan replay included) or with
+    RANGE requests mixed in (their plans run synchronously): every
+    future's Result matches ``Uruv.apply`` of the same coalesced plans,
+    sliced request by request, field for field; ``plans_range_free``
+    counts exactly the plans with no RANGE op; a request with no RANGE op
+    gets the shared read-only empty range fields; and each ``done_t`` lies
+    between its plan's settle start and the clock after the pump that
+    settled it."""
+    rng = np.random.default_rng(23)
+    prefill = OpBatch.inserts(np.arange(1, 400, 3, dtype=np.int32),
+                              np.int32(5))
+    db = Uruv(CFG)
+    db.apply(prefill)
+    co = Coalescer(db, AdmissionPolicy(start_width=32, max_width=32),
+                   record=True)
+    settle_t = {}
+    materialize = co._materialize
+
+    def recording(futs, bounds, res, plan_no):
+        t = time.monotonic()
+        for f in futs:
+            settle_t[id(f)] = t
+        return materialize(futs, bounds, res, plan_no)
+
+    co._materialize = recording
+    futs, pending = [], []
+
+    def step(drive):
+        drive()
+        now = time.monotonic()
+        for f in [f for f in pending if f.done]:
+            assert settle_t[id(f)] <= f.done_t <= now
+            pending.remove(f)
+
+    for i, req in enumerate(_request_stream(rng, 120)):
+        if with_ranges and i % 9 == 4:
+            k = int(rng.integers(1, 350))
+            rq = OpBatch.ranges([k], [k + 40])
+            if i % 2 == 0:               # two RANGE ops behind a SEARCH
+                rq = OpBatch.concat(OpBatch.searches([k]), OpBatch.ranges(
+                    [k, k + 90], [k + 40, k + 120]))
+            futs.append(co.submit(rq))
+            pending.append(futs[-1])
+        futs.append(co.submit(req))
+        pending.append(futs[-1])
+        if i % 7 == 6:
+            step(lambda: co.pump(force=True))
+    step(co.flush)
+    assert not pending and all(f.done for f in futs)
+
+    has_range = [bool((np.asarray(plan.codes) == OP_RANGE).any())
+                 for plan, _ in co.dispatch_log]
+    assert co.stats["plans"] == len(co.dispatch_log)
+    assert co.stats["plans_range_free"] == has_range.count(False)
+    assert co.stats["plans_rejected"] >= 1
+    if with_ranges:
+        assert any(has_range) and co.stats["plans_sync"] > 0
+    else:
+        assert co.stats["plans_range_free"] == co.stats["plans"]
+
+    want = _expected_results(co.dispatch_log, CFG, prefill)
+    assert len(want) == len(futs)
+    n_pages = 0
+    for f in futs:
+        got = f.result()
+        v, fd, ts, idx, pages, resume = want[id(f)]
+        for g, w in ((got.values, v), (got.found, fd), (got.timestamps, ts),
+                     (got.range_index, idx), (got.range_resume, resume)):
+            g = np.asarray(g)
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+        assert isinstance(got.range_pages, tuple)
+        assert len(got.range_pages) == len(pages)
+        for g, w in zip(got.range_pages, pages):
+            np.testing.assert_array_equal(np.asarray(g), w)
+        n_pages += len(pages)
+        if not pages:                    # the shared, read-only empties
+            assert not got.range_index.flags.writeable
+            assert not got.range_resume.flags.writeable
+    assert n_pages == sum(int((np.asarray(p.codes) == OP_RANGE).sum())
+                          for p, _ in co.dispatch_log)
+    assert (n_pages > 0) == with_ranges
